@@ -10,6 +10,7 @@ from benchmarks import (bench_decode, bench_e2e, bench_forwarding,
                         bench_pd_ratio, bench_prefill, bench_prefix_cache,
                         bench_recovery, bench_spec, bench_transfer)
 from benchmarks.common import emit
+from repro.launch.compile_cache import use_compile_cache
 
 ALL = {
     "transfer": bench_transfer,       # Fig 4, 14c/d
@@ -31,6 +32,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="", help="comma-separated subset")
     a = ap.parse_args(argv)
+    use_compile_cache()
     picks = [s for s in a.only.split(",") if s] or list(ALL)
     print("name,value,derived")
     for name in picks:

@@ -1,13 +1,12 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python with the same BlockSpec semantics; on TPU they compile
-natively. ``REPRO_KERNELS=ref`` forces the pure-jnp oracles (used by the
-engine's fallback path and for differential testing).
+On TPU the kernels always compile natively. Off TPU (the CPU tests) the
+memory-movement and decode-attention wrappers run their jitted pure-jnp
+references and flash prefill runs in interpret mode — the kernel body in
+Python with the same BlockSpec semantics.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -25,10 +24,6 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _use_ref() -> bool:
-    return os.environ.get("REPRO_KERNELS", "pallas") == "ref"
-
-
 @partial(jax.jit, static_argnames=())
 def _kv_gather_ref(storage, idx):
     return ref.kv_gather(storage, idx)
@@ -44,14 +39,14 @@ _kv_scatter_ref = jax.jit(ref.kv_scatter)
 
 
 def kv_gather(storage: jax.Array, idx: jax.Array) -> jax.Array:
-    if _use_ref() or _interpret():
+    if _interpret():
         return _kv_gather_ref(storage, idx)
     return kv_gather_pallas(storage, idx, interpret=False)
 
 
 def kv_scatter(storage: jax.Array, buf: jax.Array,
                idx: jax.Array) -> jax.Array:
-    if _use_ref() or _interpret():
+    if _interpret():
         return _kv_scatter_ref(storage, buf.astype(storage.dtype), idx)
     return kv_scatter_pallas(storage, buf, idx, interpret=False)
 
@@ -91,7 +86,7 @@ _paged_attention_ref = jax.jit(ref.paged_attention)
 def paged_attention_inline(q: jax.Array, kv_pages: jax.Array,
                            block_table: jax.Array,
                            lens: jax.Array) -> jax.Array:
-    if _use_ref() or _interpret():
+    if _interpret():
         return ref.paged_attention(q, kv_pages, block_table, lens)
     return paged_attention_pallas(q, kv_pages, block_table, lens,
                                   interpret=False)
@@ -99,15 +94,10 @@ def paged_attention_inline(q: jax.Array, kv_pages: jax.Array,
 
 def paged_attention(q: jax.Array, kv_pages: jax.Array,
                     block_table: jax.Array, lens: jax.Array) -> jax.Array:
-    if _use_ref() or _interpret():
+    if _interpret():
         return _paged_attention_ref(q, kv_pages, block_table, lens)
     return paged_attention_pallas(q, kv_pages, block_table, lens,
                                   interpret=False)
-
-
-_flash_prefill_ref = jax.jit(ref.flash_prefill,
-                             static_argnames=("q_offset", "prefix_pad",
-                                              "q_valid"))
 
 
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -118,9 +108,6 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
     defaults to q_offset; larger = a right-padded prefix bucket whose
     padded keys are masked). q_valid > 0: only the first q_valid query
     rows are real; padded queries attend to nothing (output 0)."""
-    if _use_ref():
-        return _flash_prefill_ref(q, k, v, q_offset=q_offset,
-                                  prefix_pad=prefix_pad, q_valid=q_valid)
     return flash_prefill_pallas(q, k, v, interpret=_interpret(),
                                 q_offset=q_offset, prefix_pad=prefix_pad,
                                 q_valid=q_valid)

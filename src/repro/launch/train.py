@@ -18,6 +18,7 @@ import numpy as np
 from repro.checkpoint import save_params
 from repro.configs import ALIASES, get_config
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.params import init_params, param_count_actual
 from repro.models.steps import make_train_step
 from repro.training.optimizer import AdamWConfig, adamw_init
@@ -39,6 +40,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=5)
     a = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(a.arch)
     if a.reduced:
         cfg = cfg.reduced()

@@ -35,7 +35,6 @@ engines run as fast as the hardware allows):
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -130,7 +129,7 @@ class PrefillEngine:
 
     def __init__(self, cfg: ModelConfig, params: Tree, *,
                  bucket_prefill: Optional[bool] = None,
-                 jit_prefill: Optional[bool] = None):
+                 jit_prefill: bool = True):
         self.cfg = cfg
         self.params = params
         self._attn_order = _attn_layer_order(cfg)
@@ -147,8 +146,6 @@ class PrefillEngine:
             # was retired after the bucketed default survived three
             # releases); the constructor arg remains for measurement
             bucket_prefill = True
-        if jit_prefill is None:
-            jit_prefill = os.environ.get("REPRO_PREFILL_JIT", "1") != "0"
         # bucketing serves EVERY family: the forward is pad-invariant by
         # contract (there is no per-arch gate anymore)
         self.bucket_prefill = bool(bucket_prefill)

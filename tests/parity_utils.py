@@ -3,7 +3,7 @@
 One place for the helpers that every parity suite re-derived locally
 (test_prefix_reuse, test_bucketed_prefill, test_fused_decode,
 test_state_snapshot_reuse): prompt/frame generation, the sequential
-1P:1D frontend driver, the bitwise PrefillOutput comparator, and the
+1P:1D frontend driver, the PrefillOutput comparators, and the
 decode-admission shim. Keeping them here means the parity CONTRACT is
 stated once — a suite that needs a stricter or looser comparison says
 so explicitly instead of forking a helper.
@@ -95,20 +95,42 @@ def assert_state_equal(a, b, ctx=""):
                 (ctx, key, leaf, float(np.abs(x - y).max()))
 
 
-def outputs_equal(a, b):
-    """Bitwise PrefillOutput comparison: tokens, KV, recurrent state,
-    cross-attention caches."""
+# Bucketed and exact-length prefill run the same math at different
+# padded shapes. XLA picks vectorization and reduction order per shape,
+# so the two agree to float32 rounding, not bitwise: a few ulps of the
+# O(1) activations of the reduced configs, compounded over their layers
+# and the SSD scan (at most 3.6e-7 apart on the CPU backend, every
+# family). A wrong pad mask moves values by orders of magnitude more.
+# Tokens must still agree exactly.
+CROSS_SHAPE_TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+def _close(x, y, ctx):
+    x, y = np.asarray(x), np.asarray(y)
+    assert x.dtype == y.dtype and x.shape == y.shape, \
+        (ctx, x.dtype, y.dtype, x.shape, y.shape)
+    np.testing.assert_allclose(x, y, err_msg=str(ctx), **CROSS_SHAPE_TOL)
+
+
+def outputs_close(a, b):
+    """PrefillOutput comparison across padded shapes: first token and
+    prompt length exactly; KV, recurrent state and cross-attention
+    caches to ``CROSS_SHAPE_TOL``."""
     assert a.first_token == b.first_token
     assert a.prompt_len == b.prompt_len
     if a.k is not None:
-        assert np.array_equal(np.asarray(a.k), np.asarray(b.k))
-        assert np.array_equal(np.asarray(a.v), np.asarray(b.v))
-    assert_state_equal(a.mamba_state or {}, b.mamba_state or {})
+        _close(a.k, b.k, "k")
+        _close(a.v, b.v, "v")
+    sa, sb = a.mamba_state or {}, b.mamba_state or {}
+    assert set(sa) == set(sb), set(sa) ^ set(sb)
+    for key in sorted(sa):
+        assert set(sa[key]) == set(sb[key]), key
+        for leaf in sa[key]:
+            _close(sa[key][leaf], sb[key][leaf], (key, leaf))
+    assert set(a.cross or {}) == set(b.cross or {})
     for key in (a.cross or {}):
-        assert np.array_equal(np.asarray(a.cross[key][0]),
-                              np.asarray(b.cross[key][0]))
-        assert np.array_equal(np.asarray(a.cross[key][1]),
-                              np.asarray(b.cross[key][1]))
+        _close(a.cross[key][0], b.cross[key][0], (key, "xk"))
+        _close(a.cross[key][1], b.cross[key][1], (key, "xv"))
 
 
 def decode_setup(arch, n_prompts=3, seed=5):

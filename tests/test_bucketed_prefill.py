@@ -4,10 +4,12 @@ jitted forward — exactly.
 
 The pad-invariance contract under test:
   * bucketed output is token-identical to the exact-length path, per
-    family, including the KV written for real positions;
-  * SSM/hybrid recurrent state (mamba2, jamba) is bit-identical to the
-    exact-length run (zero-dt pads are state no-ops; conv tails are
-    gathered at the valid boundary);
+    family, and the KV written for real positions matches it to float32
+    rounding (the two run at different padded shapes, see
+    ``parity_utils.CROSS_SHAPE_TOL``);
+  * SSM/hybrid recurrent state (mamba2, jamba) matches the exact-length
+    run to the same tolerance (zero-dt pads are state no-ops; conv tails
+    are gathered at the valid boundary);
   * capacity-dispatch MoE (qwen2-moe, deepseek-moe) routes identically
     under padding — window-local capacity with a valid-count threshold
     and pads force-routed to the null slot — even when experts overflow
@@ -28,7 +30,8 @@ from hypothesis import given, settings, strategies as st
 
 from conftest import ALL_ARCHS, reduced_params
 from parity_utils import make_frames as _frames, make_prompts as _prompts, \
-    outputs_equal as _outputs_equal, serve_sequential, prefill_node
+    outputs_close as _outputs_close, serve_sequential, prefill_node, \
+    CROSS_SHAPE_TOL
 from repro.kernels import ref
 from repro.serving.engine import PrefillEngine, prefill_compile_count
 
@@ -38,7 +41,8 @@ RAGGED_LENS = (5, 13, 8)
 @pytest.mark.parametrize("arch", ALL_ARCHS)
 def test_bucketed_matches_exact_per_family(arch):
     """Ragged + warm-prefix workload per family: bucketed == exact
-    (tokens, KV, mamba recurrent-state bit-identity, MoE routing), with
+    (tokens exactly; KV and mamba recurrent state to float32 rounding;
+    MoE routing), with
     the compile count pinned to the bucket set, not the length set."""
     cfg, params = reduced_params(arch)
     rng = np.random.default_rng(9)
@@ -52,7 +56,7 @@ def test_bucketed_matches_exact_per_family(arch):
     o_b = bucketed.run(prompts, frames=frames)
     bucket_compiles = prefill_compile_count() - c0
     for a, b in zip(o_e, o_b):
-        _outputs_equal(a, b)
+        _outputs_close(a, b)
     # accounting stays exact; padding is ledgered separately
     assert exact.compute_tokens == bucketed.compute_tokens \
         == sum(RAGGED_LENS)
@@ -84,7 +88,8 @@ def test_bucketed_matches_exact_per_family(arch):
     warm = bucketed.run_suffix(long[plen:], pkv,
                                frames=fr[0] if fr else None)
     assert warm.first_token == cold.first_token
-    assert np.array_equal(np.asarray(warm.k), np.asarray(cold.k))
+    np.testing.assert_allclose(np.asarray(warm.k), np.asarray(cold.k),
+                               **CROSS_SHAPE_TOL)
     assert warm.prompt_len == cold.prompt_len
 
 
@@ -144,7 +149,9 @@ def test_capacity_moe_drops_are_pad_invariant():
         for name, a in leaves.items():
             b = np.asarray(c_p["layers"][sub][name])[:, :, :ln] \
                 if name in ("k", "v") else np.asarray(c_p["layers"][sub][name])
-            assert np.array_equal(np.asarray(a), b), (sub, name)
+            np.testing.assert_allclose(np.asarray(a), b,
+                                       err_msg=f"{sub}/{name}",
+                                       **CROSS_SHAPE_TOL)
 
 
 def test_capacity_moe_warm_prefix_matches_cold_serving():
