@@ -54,6 +54,10 @@ class ServeRequest:
     submit_t: float = -1.0           # gateway arrival
     first_token_t: float = -1.0      # prefill batch completion (TTFT end)
     finish_t: float = -1.0           # last decode token (TPOT window end)
+    # host-clock stamps (time.perf_counter()): the first token leaves
+    # prefill, the request is admitted to a decode slot
+    wall_first_token: float = -1.0
+    wall_admit: float = -1.0
     # fault tolerance (serving/faults.py): an SLO deadline in virtual
     # seconds after submit (<0 == none); recovery sheds a request whose
     # deadline already passed instead of re-admitting it, and counts
@@ -114,7 +118,6 @@ class PrefillNode:
         self.prefill_scale = 1.0
         self.decode_scale = 1.0
         self._batch_evt = False      # a "batch" event is already queued
-        self._evictions_seen = 0     # pool evictions already ledgered
         # layer-streaming mode (overlapped transfer): per-rid payloads
         # {attn_layer -> (tokens, width) kv stripe} and batch timing
         self.staged: Dict[int, Dict[int, object]] = {}

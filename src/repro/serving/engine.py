@@ -50,6 +50,7 @@ from repro.models.modeling import (
     decode_step_jit, forward_prefill, lm_logits, mamba_sublayer_step,
     rmsnorm, rope, spec_decode_step_jit)
 from repro.models.params import block_period, num_blocks
+from repro.serving import trace
 from repro.serving.kvcache import PagedKVPool
 from repro.serving.speculative import SpecConfig
 
@@ -122,9 +123,9 @@ class PrefillEngine:
     tokens. ``compute_tokens`` counts real prompt tokens pushed through
     the forward pass — bucket padding is tracked separately in
     ``padded_tokens`` (the parity tests and benchmarks assert savings on
-    the exact counter). ``prefill_batches`` / ``bucket_hits`` ledger how
-    often a batch landed on an already-seen shape bucket (a compile-
-    cache hit for this engine) — the frontend's compile-stall telemetry.
+    the exact counter). ``prefill_batches`` counts jitted batch
+    launches; the programs they build are counted by
+    ``serving/trace.py``.
     """
 
     def __init__(self, cfg: ModelConfig, params: Tree, *,
@@ -156,10 +157,8 @@ class PrefillEngine:
         self.prefix_prefills = 0     # suffix-only prefills executed
         self.state_restores = 0      # warm runs seeded from a snapshot
         self.prefill_batches = 0     # jitted batch launches
-        self.bucket_hits = 0         # launches on an already-seen shape
         self.chunked_prefills = 0    # prompts completed via iter_chunks
         self.chunked_chunks = 0      # individual chunk launches
-        self._shapes_seen: set = set()
 
     def _prefill(self, batch: Tree, *, last_index: jax.Array,
                  prefix: Optional[Tree] = None, prefix_len: int = 0,
@@ -249,13 +248,6 @@ class PrefillEngine:
             b *= 2
         return min(b, max(self.cfg.max_seq_len, n))
 
-    def _count_launch(self, shape_key: Tuple) -> None:
-        self.prefill_batches += 1
-        if shape_key in self._shapes_seen:
-            self.bucket_hits += 1
-        else:
-            self._shapes_seen.add(shape_key)
-
     def run(self, token_lists: Sequence[Sequence[int]],
             frames: Optional[Sequence] = None,
             on_layer: Optional[OnLayer] = None,
@@ -312,7 +304,7 @@ class PrefillEngine:
         batch = {"tokens": jnp.asarray(toks)}
         self.compute_tokens += sum(lens)
         self.padded_tokens += b * s - sum(lens)
-        self._count_launch((b, s, snap_stride))
+        self.prefill_batches += 1
         if cfg.is_encoder_decoder:
             assert frames is not None, "enc-dec prefill needs frames"
             batch["frames"] = jnp.stack([jnp.asarray(f) for f in frames])
@@ -499,7 +491,7 @@ class PrefillEngine:
         self.prefix_prefills += 1
         if state is not None:
             self.state_restores += 1
-        self._count_launch(("suffix", p_pad, s_pad, snap_stride))
+        self.prefill_batches += 1
         layers = cache["layers"]
         k = v = None
         if self._attn_order:
@@ -768,22 +760,23 @@ class DecodeEngine:
         """Push host slot mirrors into the fixed-shape device arrays.
         Runs only after admissions/evictions (membership changes) — the
         steady-state fused loop touches no host state on the way in."""
-        need = max((len(self.pool.owned(r)) for r in self.rid
-                    if r is not None), default=1)
-        while self._table_w < need:
-            self._table_w *= 2
-        self._tokens = jnp.asarray(self.last_tok)
-        self._pos = jnp.asarray(self.pos.astype(np.int32))
-        self._active = jnp.asarray(
-            np.asarray([r is not None for r in self.rid]))
-        self._table = jnp.asarray(
-            self.pool.block_tables(list(self.rid), self._table_w))
-        bs = self.pool.block_size
-        self._caps = np.asarray(
-            [len(self.pool.owned(r)) * bs if r is not None else 0
-             for r in self.rid], np.int64)
-        self._caps_dev = jnp.asarray(self._caps.astype(np.int32))
-        self._dirty = False
+        with trace.span("pd.decode.upload"):
+            need = max((len(self.pool.owned(r)) for r in self.rid
+                        if r is not None), default=1)
+            while self._table_w < need:
+                self._table_w *= 2
+            self._tokens = jnp.asarray(self.last_tok)
+            self._pos = jnp.asarray(self.pos.astype(np.int32))
+            self._active = jnp.asarray(
+                np.asarray([r is not None for r in self.rid]))
+            self._table = jnp.asarray(
+                self.pool.block_tables(list(self.rid), self._table_w))
+            bs = self.pool.block_size
+            self._caps = np.asarray(
+                [len(self.pool.owned(r)) * bs if r is not None else 0
+                 for r in self.rid], np.int64)
+            self._caps_dev = jnp.asarray(self._caps.astype(np.int32))
+            self._dirty = False
 
     def _step_fused(self) -> Dict[int, int]:
         act = self.active_slots()
@@ -811,7 +804,8 @@ class DecodeEngine:
         self._slot_layers = layers
         self._tokens, self._pos = toks, pos
         self.fused_steps += 1
-        out_np = np.asarray(nxt)             # the ONE host sync per step
+        with trace.span("pd.decode.readback"):
+            out_np = np.asarray(nxt)         # the ONE host sync per step
         out: Dict[int, int] = {}
         for s_i in act:
             self.pos[s_i] += 1

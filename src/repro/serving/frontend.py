@@ -20,8 +20,8 @@ ServeGroup.prefix_stats() aggregates hit-rate / reused-token counters.
 
 The serving core is TICKLESS: the TransferScheduler's virtual-time
 event queue is the spine of the group. Request arrivals, prefill-batch
-completions, per-layer KV segment landings, decode steps, drained role
-flips and prefix-cache evictions are all timestamped events drained in
+completions, per-layer KV segment landings, decode steps and drained
+role flips are all timestamped events drained in
 nondecreasing virtual time (ClusterFrontend.serve merges every group's
 frontier plus the gateway arrival queue onto one shared timeline), so
 TTFT/TPOT are ledgered in virtual SECONDS — the goodput currency of the
@@ -66,6 +66,7 @@ from repro.core.transfer import KVTransferEngine, LinkModel
 from repro.core.zookeeper import MetaStore
 from repro.models.config import ModelConfig
 from repro.models.params import init_params
+from repro.serving import trace
 from repro.serving.cluster import DecodeNode, PrefillNode, ServeRequest
 from repro.serving.engine import prefill_compile_count
 from repro.serving.transfer_sched import (TransferJob, TransferScheduler,
@@ -121,8 +122,7 @@ class ServeGroup:
                       (self-rescheduling while the node has requests);
       * ``segment`` — a per-layer KV stripe (or trailing state payload)
                       landed on a link (drained via scheduler pump);
-      * ``pump``    — bare scheduler retry point (waiting_dst jobs);
-      * ``evict``   — a prefix-cache block eviction (observability).
+      * ``pump``    — bare scheduler retry point (waiting_dst jobs).
 
     ``event_log`` records drained events as (t, kind), nondecreasing in
     t while the tickless loop drives the group (property-tested)."""
@@ -407,11 +407,14 @@ class ServeGroup:
         return tgt if tgt is not None else self._pick_decode()
 
     def _on_admit(self, job: TransferJob):
-        job.dst.finish_admit(job.req, job.out)
-        self.gen_tokens.append(job.req.max_new_tokens)
-        if self._tickless:
-            self._schedule_step(job.dst,
-                                max(job.admitted_t, job.dst.busy_until))
+        with trace.span("pd.xfer.admit"):
+            job.dst.finish_admit(job.req, job.out)
+            if job.req.wall_admit < 0.0:
+                job.req.wall_admit = time.perf_counter()
+            self.gen_tokens.append(job.req.max_new_tokens)
+            if self._tickless:
+                self._schedule_step(job.dst,
+                                    max(job.admitted_t, job.dst.busy_until))
 
     # ------------------------------------------------------- event core
     def schedule(self, t: float, kind: str, obj: object = None):
@@ -453,11 +456,11 @@ class ServeGroup:
                     and (t_ev is None or t_sc <= t_ev):
                 self.vclock = max(self.vclock, t_sc)
                 self.event_log.append((t_sc, "segment"))
-                self.sched.pump(t_sc)
+                self._pump(t_sc)
             elif t_ev is not None and t_ev <= until:
                 t, _, kind, obj = heapq.heappop(self.events)
                 if self.sched is not None:
-                    self.sched.pump(t)
+                    self._pump(t)
                 self.vclock = max(self.vclock, t)
                 self.event_log.append((t, kind))
                 self._dispatch(kind, t, obj)
@@ -473,10 +476,14 @@ class ServeGroup:
         while self.events:
             t, _, kind, obj = heapq.heappop(self.events)
             if self.sched is not None:
-                self.sched.pump(t)
+                self._pump(t)
             self.vclock = max(self.vclock, t)
             self.event_log.append((t, kind))
             self._dispatch(kind, t, obj)
+
+    def _pump(self, t: float):
+        with trace.span("pd.xfer.pump"):
+            self.sched.pump(t)
 
     def _dispatch(self, kind: str, t: float, obj: object):
         if kind == "batch":
@@ -494,7 +501,7 @@ class ServeGroup:
             if self.ft is not None:
                 self.ft.dispatch(kind, t, obj)
         # "pump": the pre-dispatch pump already retried waiting jobs;
-        # "evict"/"segment" are ledger-only kinds
+        # "segment" is a ledger-only kind
 
     # ------------------------------------------------------- handlers
     def _ev_batch(self, t: float, p: PrefillNode):
@@ -510,9 +517,11 @@ class ServeGroup:
             return
         batch_rids = [r.rid for r in p.forming]
         batch_tokens = sum(len(r.tokens) for r in p.forming)
-        t0 = time.perf_counter()
-        ready = p.run_batch(collect_layers=self.overlap_transfer)
-        w = time.perf_counter() - t0
+        with trace.span("pd.prefill.batch"):
+            t0 = time.perf_counter()
+            ready = p.run_batch(collect_layers=self.overlap_transfer)
+            t1 = time.perf_counter()
+        w = t1 - t0
         if self.service_model is not None:
             # deterministic chaos runs: charge the model's virtual cost,
             # not the jittery measured wall time
@@ -532,9 +541,9 @@ class ServeGroup:
             # stamp: TTFT ended when the first prefill streamed it
             if req.first_token_t < 0.0:
                 req.first_token_t = done
+                req.wall_first_token = t1
                 if req.submit_t >= 0.0:
                     self.ttft_s.append(max(0.0, done - req.submit_t))
-        self._note_evictions(p, t)
         # overlapped: the engine streams layers DURING the compute
         # window, so the hand-off (scheduler begin) is stamped at batch
         # start and segments land under the window (Fig. 10); blocking
@@ -583,6 +592,8 @@ class ServeGroup:
             else:
                 tgt.admit(req, out, p.pool, self.xfer,
                           mode=self.transfer_mode)
+                if req.wall_admit < 0.0:
+                    req.wall_admit = time.perf_counter()
                 stall = self.xfer.stats[-1].time_s if out.k is not None \
                     else 0.0
                 state_b = state_payload_nbytes(out)
@@ -616,9 +627,10 @@ class ServeGroup:
             self._schedule_step(d, d.busy_until)
             return
         n_slots = len(d.requests)
-        t0 = time.perf_counter()
-        finished = d.step()
-        w = time.perf_counter() - t0
+        with trace.span("pd.decode.step"):
+            t0 = time.perf_counter()
+            finished = d.step()
+            w = time.perf_counter() - t0
         if self.service_model is not None:
             w = self.service_model.decode_step_s(n_slots)
         w *= d.decode_scale
@@ -675,7 +687,8 @@ class ServeGroup:
             return
         t0 = time.perf_counter()
         n_chunk, out = next(job.chunks)
-        w = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        w = t1 - t0
         if self.service_model is not None:
             w = self.service_model.prefill_batch_s(n_chunk)
         w *= d.prefill_scale            # decode iron runs prefill slower
@@ -697,6 +710,7 @@ class ServeGroup:
                 out.k, out.v)
         if req.first_token_t < 0.0:
             req.first_token_t = done
+            req.wall_first_token = t1
             if req.submit_t >= 0.0:
                 self.ttft_s.append(max(0.0, done - req.submit_t))
         req.generated.append(out.first_token)
@@ -713,15 +727,11 @@ class ServeGroup:
             self._trim_hists()
             return
         d.finish_admit(req, out)
+        if req.wall_admit < 0.0:
+            req.wall_admit = time.perf_counter()
         if self._tickless:
             self._schedule_step(d, done)
         self._trim_hists()
-
-    def _note_evictions(self, p: PrefillNode, t: float):
-        new = p.pool.evictions - p._evictions_seen
-        p._evictions_seen = p.pool.evictions
-        for _ in range(int(new)):
-            self.event_log.append((t, "evict"))
 
     def _trim_hists(self):
         for hist in (self.prefill_batch_s, self.decode_step_s,
@@ -757,7 +767,7 @@ class ServeGroup:
         self._drain_queued()
         # completed last layers fire decode admission
         if self.sched is not None:
-            self.sched.pump(self.vclock)
+            self._pump(self.vclock)
         for d in self.decodes:
             if d.requests:
                 self.event_log.append((self.vclock, "step"))
@@ -904,9 +914,8 @@ class ServeGroup:
 
         Prefill compile-stall telemetry rides along: the SHARED jitted
         prefill's live compile count (cluster-wide, O(num_buckets) under
-        bucketing), this group's bucket hit rate (fraction of batch
-        launches landing on an already-compiled shape — misses are
-        compile stalls the RatioAdjuster/benchmarks can now see) and the
+        bucketing), the programs built inside prefill batches while
+        tracing is on (cluster-wide, ``serving/trace.py``) and the
         pad-waste ratio (bucket-padding tokens over all tokens pushed
         through the forward)."""
         if self.sched is not None:
@@ -927,12 +936,12 @@ class ServeGroup:
         out["prefill_batch_median_s"] = _median(self.prefill_batch_s[-32:])
         engines = [p.engine for p in self.prefills]
         batches = sum(e.prefill_batches for e in engines)
-        hits = sum(e.bucket_hits for e in engines)
         comp = sum(e.compute_tokens for e in engines)
         padt = sum(e.padded_tokens for e in engines)
         out["prefill_compile_count"] = float(prefill_compile_count())
         out["prefill_batches"] = float(batches)
-        out["prefill_bucket_hit_rate"] = hits / batches if batches else 0.0
+        out["prefill_builds"] = float(
+            trace.builds().get("pd.prefill.batch", 0))
         out["prefill_pad_waste"] = padt / (comp + padt) \
             if comp + padt else 0.0
         for k, v in self.absorbs.items():   # chunked-prefill elasticity
@@ -1393,8 +1402,9 @@ class ClusterFrontend:
                             self.autoscaler.note_arrival(
                                 self.group_for(req).scenario, t_arr,
                                 gen_tokens=req.max_new_tokens)
-                        if not self._try_place(req, t_arr):
-                            self._gw_requeue(req, t_arr)
+                        with trace.span("pd.gateway.place"):
+                            if not self._try_place(req, t_arr):
+                                self._gw_requeue(req, t_arr)
                 else:
                     if deadline is not None and t_grp > deadline:
                         break

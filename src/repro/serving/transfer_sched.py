@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.transfer import LinkModel, layer_slices
+from repro.serving import trace
 
 
 @dataclass
@@ -182,61 +183,63 @@ class TransferScheduler:
         as streamed by PrefillEngine's layer mode; when omitted they are
         sliced from ``out.k``/``out.v``. ``fracs`` are the engine's
         network-depth layer fractions (uniform if omitted)."""
-        rid = req.rid
-        pool = dst.pool
-        total = out.prompt_len + getattr(req, "max_new_tokens", 0) + 1
-        dst_blocks = pool.alloc(rid, total)
-        n_kv = pool.blocks_for_tokens(out.prompt_len) \
-            if out.k is not None else 0
-        segments: List[Segment] = []
-        buf: Dict[int, jax.Array] = {}
-        prefill_done = t_start + compute_s
-        if n_kv:
-            L = int(out.k.shape[0])
-            if fracs is None:
-                fracs = [(i + 1) / L for i in range(L)]
-            stripe = pool.layer_nbytes(n_kv)
-            slices = layer_slices(L, L * stripe)
-            pad = n_kv * pool.block_size - out.prompt_len
-            for li in range(L):
-                if payloads is not None and li in payloads:
-                    row = payloads[li]
-                    if row.shape[-1] == out.k.shape[-1]:  # split k half only
-                        row = jnp.concatenate([row, out.v[li]], axis=-1)
-                else:
-                    row = jnp.concatenate([out.k[li], out.v[li]], axis=-1)
-                if pad:
-                    row = jnp.pad(row, ((0, pad), (0, 0)))
-                buf[li] = row
-                off, ln = slices[li]
+        with trace.span("pd.xfer.begin"):
+            rid = req.rid
+            pool = dst.pool
+            total = out.prompt_len + getattr(req, "max_new_tokens", 0) + 1
+            dst_blocks = pool.alloc(rid, total)
+            n_kv = pool.blocks_for_tokens(out.prompt_len) \
+                if out.k is not None else 0
+            segments: List[Segment] = []
+            buf: Dict[int, jax.Array] = {}
+            prefill_done = t_start + compute_s
+            if n_kv:
+                L = int(out.k.shape[0])
+                if fracs is None:
+                    fracs = [(i + 1) / L for i in range(L)]
+                stripe = pool.layer_nbytes(n_kv)
+                slices = layer_slices(L, L * stripe)
+                pad = n_kv * pool.block_size - out.prompt_len
+                for li in range(L):
+                    if payloads is not None and li in payloads:
+                        row = payloads[li]
+                        if row.shape[-1] == out.k.shape[-1]:
+                            # split k half only
+                            row = jnp.concatenate([row, out.v[li]], axis=-1)
+                    else:
+                        row = jnp.concatenate([out.k[li], out.v[li]], axis=-1)
+                    if pad:
+                        row = jnp.pad(row, ((0, pad), (0, 0)))
+                    buf[li] = row
+                    off, ln = slices[li]
+                    segments.append(Segment(
+                        layer=li, offset=off, nbytes=ln,
+                        ready_t=t_start + fracs[li] * compute_s))
+            state_bytes = state_payload_nbytes(out)
+            if state_bytes:
+                # the recurrent/cross state is only final once the whole
+                # forward is done: it ships last, alongside the KV payload.
+                # Warm (prefix-reuse) SSM admissions ship the RESTORED state
+                # advanced over the suffix — out.mamba_state comes straight
+                # from run_suffix's snapshot-seeded forward, never a
+                # recompute of the cached prefix
                 segments.append(Segment(
-                    layer=li, offset=off, nbytes=ln,
-                    ready_t=t_start + fracs[li] * compute_s))
-        state_bytes = state_payload_nbytes(out)
-        if state_bytes:
-            # the recurrent/cross state is only final once the whole
-            # forward is done: it ships last, alongside the KV payload.
-            # Warm (prefix-reuse) SSM admissions ship the RESTORED state
-            # advanced over the suffix — out.mamba_state comes straight
-            # from run_suffix's snapshot-seeded forward, never a
-            # recompute of the cached prefix
-            segments.append(Segment(
-                layer=-1, offset=sum(s.nbytes for s in segments),
-                nbytes=state_bytes, ready_t=prefill_done))
-            self.state_segments += 1
-            self.state_bytes += state_bytes
-        job = TransferJob(
-            rid=rid, req=req, out=out, src_iid=src_iid, dst=dst,
-            dst_blocks=dst_blocks, n_kv_blocks=n_kv, segments=segments,
-            buf=buf, t_start=t_start, compute_s=compute_s,
-            prefill_done_t=prefill_done, on_admit=on_admit)
-        self.jobs.append(job)
-        if segments:
-            link = self._link(src_iid, dst.iid)
-            link.queue.extend((job, s) for s in segments)
-        else:
-            self._admit(job, prefill_done)
-        return job
+                    layer=-1, offset=sum(s.nbytes for s in segments),
+                    nbytes=state_bytes, ready_t=prefill_done))
+                self.state_segments += 1
+                self.state_bytes += state_bytes
+            job = TransferJob(
+                rid=rid, req=req, out=out, src_iid=src_iid, dst=dst,
+                dst_blocks=dst_blocks, n_kv_blocks=n_kv, segments=segments,
+                buf=buf, t_start=t_start, compute_s=compute_s,
+                prefill_done_t=prefill_done, on_admit=on_admit)
+            self.jobs.append(job)
+            if segments:
+                link = self._link(src_iid, dst.iid)
+                link.queue.extend((job, s) for s in segments)
+            else:
+                self._admit(job, prefill_done)
+            return job
 
     # ---------------------------------------------------------- failures
     def fail_node(self, iid: str):
@@ -397,9 +400,10 @@ class TransferScheduler:
         seg.delivered = True
         if seg.layer >= 0:
             # RecvScatter of this layer's stripe into the dst blocks
-            job.dst.pool.scatter_layer(job.buf[seg.layer],
-                                       job.dst_blocks[:job.n_kv_blocks],
-                                       seg.layer)
+            with trace.span("pd.xfer.scatter"):
+                job.dst.pool.scatter_layer(job.buf[seg.layer],
+                                           job.dst_blocks[:job.n_kv_blocks],
+                                           seg.layer)
         # state payload (layer == -1) rides on job.out and is applied at
         # admission (DecodeEngine.admit): only its wire time is modeled
         if all(s.delivered for s in job.segments):

@@ -33,6 +33,8 @@ from parity_utils import make_frames as _frames, make_prompts as _prompts, \
     outputs_close as _outputs_close, serve_sequential, prefill_node, \
     CROSS_SHAPE_TOL
 from repro.kernels import ref
+from repro.serving import trace
+from repro.serving.cluster import ServeRequest
 from repro.serving.engine import PrefillEngine, prefill_compile_count
 
 RAGGED_LENS = (5, 13, 8)
@@ -69,7 +71,10 @@ def test_bucketed_matches_exact_per_family(arch):
     frames2 = _frames(cfg, rng, 3)
     o_w = bucketed.run(wave2, frames=frames2)
     assert prefill_compile_count() == c1
-    assert bucketed.bucket_hits >= 1     # telemetry saw the shape reuse
+    # the build counter: the same wave again builds no program at all
+    b1 = trace.build_count()
+    bucketed.run(wave2, frames=frames2)
+    assert trace.build_count() == b1
     ref_w = exact.run(wave2, frames=frames2)
     for a, b in zip(ref_w, o_w):
         assert a.first_token == b.first_token
@@ -179,8 +184,19 @@ def test_capacity_moe_warm_prefix_matches_cold_serving():
     # compile-stall telemetry rides on the group ledger
     ts = g.transfer_stats()
     assert ts["prefill_compile_count"] >= 1.0
-    assert 0.0 <= ts["prefill_bucket_hit_rate"] <= 1.0
     assert ts["prefill_batches"] == float(node.engine.prefill_batches)
+    # builds inside prefill batches (counted while tracing is on): a warm
+    # request of a shape already served builds none
+    again = ServeRequest(rid=len(prompts), max_new_tokens=3, tokens=prefix
+                         + list(map(int, rng.integers(0, cfg.vocab_size,
+                                                      5))))
+    trace.enable()
+    try:
+        fe.run([again])
+    finally:
+        trace.enable(False)
+    assert again.done
+    assert g.transfer_stats()["prefill_builds"] == ts["prefill_builds"]
     # pad waste only exists on the bucketed default (an engine built
     # with bucket_prefill=False pads nothing)
     assert 0.0 <= ts["prefill_pad_waste"] < 1.0

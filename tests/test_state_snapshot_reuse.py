@@ -25,6 +25,7 @@ import pytest
 from conftest import reduced_params
 from parity_utils import BS, admit, assert_state_equal, prefill_node, \
     serve_sequential
+from repro.serving import trace
 from repro.serving.engine import DecodeEngine, PrefillEngine, \
     prefill_compile_count
 from repro.serving.kvcache import PagedKVPool
@@ -202,12 +203,12 @@ def test_second_wave_reuses_compiled_suffix_program():
                   state=cold1.snapshots[stride], prefix_len=stride,
                   snap_stride=stride)
     c0 = prefill_compile_count()
-    hits0 = pe.bucket_hits
+    b0 = trace.build_count()
     warm2 = pe.run_suffix(p2[stride:], _pkv(cold2, stride),
                           state=cold2.snapshots[stride], prefix_len=stride,
                           snap_stride=stride)
     assert prefill_compile_count() == c0          # no retrace
-    assert pe.bucket_hits == hits0 + 1            # telemetry saw reuse
+    assert trace.build_count() == b0              # no build at all
     assert warm2.first_token == cold2.first_token
     assert_state_equal(cold2.mamba_state, warm2.mamba_state)
 
