@@ -15,7 +15,7 @@ from jax import lax
 
 from repro.kernels import ref
 from repro.kernels.kv_gather import kv_gather_pallas
-from repro.kernels.kv_scatter import kv_scatter_pallas
+from repro.kernels.kv_scatter import kv_scatter_layer_pallas, kv_scatter_pallas
 from repro.kernels.flash_prefill import flash_prefill_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 
@@ -53,10 +53,8 @@ def kv_scatter(storage: jax.Array, buf: jax.Array,
 
 # Per-layer-triggered transfer (paper Fig. 10): move ONE layer's stripe
 # of the linearized buffer while later layers are still prefilling. The
-# layer slice is taken OUTSIDE the kernel (a zero-copy lax.slice on the
-# leading axis), so the same gather/scatter kernels serve both the
-# whole-buffer and per-layer paths — on TPU they compile natively over
-# the single-layer view, off-TPU they route to the jitted bitwise ref.
+# sender takes the layer slice OUTSIDE the kernel (a lax.slice on the
+# leading axis), so the whole-buffer gather kernel serves it too.
 
 def kv_gather_layer(storage: jax.Array, idx: jax.Array,
                     layer: int) -> jax.Array:
@@ -65,12 +63,25 @@ def kv_gather_layer(storage: jax.Array, idx: jax.Array,
                      idx)[0]
 
 
+# The receiver is one jitted program per (pool shape, block count): the
+# layer is a traced operand, so every layer's scatter of an n-block
+# stripe reuses the same build. The pool is donated and the program
+# writes only the n destination blocks in place — no layer slice, no
+# write-back of the layer into the pool. On TPU the body is the
+# per-layer Pallas kernel (pool aliased to the output); off TPU it is
+# the bitwise jnp ref, so the CPU tests cover the same wrapper and the
+# same donation.
+@partial(jax.jit, donate_argnums=0)
 def kv_scatter_layer(storage: jax.Array, buf: jax.Array, idx: jax.Array,
-                     layer: int) -> jax.Array:
-    """Scatter one layer's (n*BS, W) stripe back into paged storage."""
-    row = kv_scatter(lax.slice_in_dim(storage, layer, layer + 1, axis=0),
-                     buf[None], idx)
-    return lax.dynamic_update_slice_in_dim(storage, row, layer, axis=0)
+                     layer: jax.Array) -> jax.Array:
+    """Scatter one layer's (n*BS, W) stripe into blocks ``idx`` of
+    ``layer`` of the donated (L, NB, BS, W) pool; returns the pool."""
+    buf = buf.astype(storage.dtype)
+    if _interpret():
+        n, (_, _, bs, w) = idx.shape[0], storage.shape
+        return storage.at[layer, idx].set(buf.reshape(n, bs, w))
+    return kv_scatter_layer_pallas(storage, buf, idx, layer,
+                                   interpret=False)
 
 
 # Decode attention routes like kv_gather/kv_scatter: off-TPU the jitted

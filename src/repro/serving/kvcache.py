@@ -506,12 +506,14 @@ class PagedKVPool:
     def scatter_layer(self, buf: jax.Array, blocks: Sequence[int],
                       layer: int):
         """RecvScatter of ONE layer's stripe into discrete blocks — the
-        per-layer-triggered receiver side."""
+        per-layer-triggered receiver side. The kernel path donates the
+        storage: the returned buffer replaces it in this one assignment,
+        and no other holder may keep the old one."""
         from repro.kernels import ops
         idx = jnp.asarray(list(blocks), jnp.int32)
         if self.use_kernels:
-            self.storage = ops.kv_scatter_layer(
-                self.storage, buf.astype(self.dtype), idx, layer)
+            self.storage = ops.kv_scatter_layer(self.storage, buf, idx,
+                                                layer)
         else:
             t, w = buf.shape
             n = len(blocks)

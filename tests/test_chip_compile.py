@@ -15,6 +15,7 @@ several test workers only the worker given this file may try.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +98,28 @@ def test_kernel_compiles_natively(one_chip, name):
     compiled = jax.jit(functools.partial(kernel, interpret=False)) \
         .lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_per_layer_scatter_updates_the_donated_pool_in_place(one_chip,
+                                                              monkeypatch):
+    """The KV hand-off's per-layer RecvScatter at the benchmark cell's
+    decode pool (8 layers, 2400 blocks of 16, width 2048, float32): one
+    program with the layer traced, the Pallas kernel inside, the donated
+    pool aliased to the output and never copied whole."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    L, nb, n = 8, 2400, 32
+    pool = (L, nb, BS, W)
+    compiled = ops.kv_scatter_layer.lower(
+        _sds(pool, jnp.float32, one_chip),
+        _sds((n * BS, W), jnp.float32, one_chip),
+        _sds((n,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "input_output_alias={ {}: (0, {}" in text
+    assert not re.search(rf"= f32\[{L},{nb},{BS},{W}\]\S* copy\(", text)
+    pool_bytes = 4 * L * nb * BS * W
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 100
 
 
 def _granite(layers):
