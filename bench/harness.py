@@ -5,10 +5,14 @@ can drive them on the CPU at a reduced size. A cell is an entry of
 ``BENCHMARK.json``'s ``workloads``; everything else is found by name:
 
 - ``bench/configs/<config>.json``: the model's sizes, its precision and
-  how it is served (topology, pool and slot sizes);
+  how it is served (topology, pool and slot sizes), and its family;
+- ``bench/families/<family>.py``: the model's shapes, weights, plain
+  reference and work counts (``bench/families/__init__.py``);
 - ``bench/traffic/<mix>.json``: the traffic, read by
   ``bench/traffic/gen_<generator>.py``;
 - ``bench/metrics/<metric>.py``: one reader per per-layer metric.
+
+Each is looked up under the checkout root that ``load_cell`` is given.
 
 The run drives the real serving path, ``ClusterFrontend.submit`` and
 ``ClusterFrontend.serve``, one event at a time, and stamps every token
@@ -37,7 +41,7 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 
 import jax  # noqa: E402
 
-from bench import reference, weights  # noqa: E402
+from bench import families, reference, weights  # noqa: E402
 
 # Correctness: at every served position of every finished request, the
 # gap by which the served token's logit lies below the best logit of the
@@ -71,6 +75,7 @@ class Cell:
     mix: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    root: Path
 
 
 def load_json(path: Path) -> Dict:
@@ -91,9 +96,11 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     per = [m for m in bench["per_layer"]
            if (name in m["workloads"] if "workloads" in m
                else m["moves"] in moves)]
-    return Cell(name, int(w["chips"]), load_json(root / cfg["file"]),
+    config = families.attach(load_json(root / cfg["file"]), root,
+                             cfg["file"])
+    return Cell(name, int(w["chips"]), config,
                 load_json(root / "bench" / "traffic" / f"{w['traffic']}.json"),
-                e2e, per)
+                e2e, per, root)
 
 
 def load_module(path: Path):
@@ -105,30 +112,17 @@ def load_module(path: Path):
 
 
 def generate(cell: Cell, seed: int, seconds: float) -> Dict:
-    gen = load_module(BENCH / "traffic"
+    gen = load_module(cell.root / "bench" / "traffic"
                       / f"gen_{cell.mix['generator']}.py")
     return gen.generate(cell.mix, seed=seed, seconds=seconds,
                         vocab=cell.config["vocab_size"])
 
 
-def program_config(c: Dict):
-    """The serving program's config for a configuration file."""
-    from repro.models.config import ModelConfig
-    m = weights.dims(c)
-    return ModelConfig(
-        name=c["name"], arch_type="dense", num_layers=m["layers"],
-        d_model=m["d"], num_heads=m["nh"], num_kv_heads=m["nkv"],
-        d_ff=m["ff"], vocab_size=m["vocab"], head_dim=m["hd"],
-        qkv_bias=bool(c.get("attention_bias", False)),
-        rope_theta=float(c["rope_theta"]), norm_eps=c["rms_norm_eps"],
-        tie_embeddings=m["tied"],
-        max_seq_len=int(c["max_position_embeddings"]))
-
-
 def check_weights(c: Dict, params) -> None:
     """The benchmark's weight tree has the program's layout."""
     from repro.models.params import abstract_params
-    want = jax.tree.map(lambda s: s.shape, abstract_params(program_config(c)))
+    want = jax.tree.map(lambda s: s.shape,
+                        abstract_params(families.of(c).program_config(c)))
     got = jax.tree.map(lambda a: a.shape, params)
     if want != got:
         raise SystemExit(f"weight layout differs from the program's: "
@@ -205,7 +199,8 @@ def build(cell: Cell, params, seed: int):
     s = cell.config["serve"]
     n_p, n_d = s["topology"]
     return ClusterFrontend(
-        program_config(cell.config), topology={"default": (n_p, n_d)},
+        families.of(cell.config).program_config(cell.config),
+        topology={"default": (n_p, n_d)},
         params=params, seed=seed, flat_iids=True, overlap_transfer=True,
         prefill_kwargs=dict(s["prefill"]), decode_kwargs=dict(s["decode"]))
 

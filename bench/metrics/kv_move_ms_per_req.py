@@ -1,4 +1,5 @@
-"""KV transfer: device milliseconds of the KV gather and scatter programs
+"""KV transfer: device milliseconds of the per-layer KV scatter into the
+decode pool (``jit_kv_scatter_layer``, one run per layer of a request)
 per request handed from prefill to decode in the window."""
 from bench.metrics_common import KV_MOVE_MODULES, module_time
 

@@ -2,11 +2,12 @@
 
 Programs are matched by their module name in the device trace (a jitted
 function ``f`` runs as ``jit_f``), operations by ``"<program>|<op>
-<opcode>"`` (a tuple pattern needs all of its parts). The transfer path
-runs eagerly: its Pallas scatter dispatches as ``jit_wrapped``, the layer
-slice and the write back into the pool as ``jit_slice`` and
-``jit_dynamic_update_slice``; the paged attention kernel is the one
-Pallas kernel (``tpu_custom_call``) inside the fused decode step.
+<opcode>"`` (a tuple pattern needs all of its parts). The KV hand-off's
+per-layer scatter into the decode pool is one cached program per block
+count, ``jit_kv_scatter_layer`` (``kernels/ops.kv_scatter_layer``), which
+writes the received blocks of one layer into the donated pool in place;
+the paged attention kernel is the one Pallas kernel (``tpu_custom_call``)
+inside the fused decode step.
 """
 from __future__ import annotations
 
@@ -16,7 +17,7 @@ from bench import trace_reduce
 
 DECODE_MODULES = ("jit_forward_decode_step",)
 PREFILL_MODULES = ("jit_forward_prefill",)
-KV_MOVE_MODULES = ("jit_wrapped", "jit_slice", "jit_dynamic_update_slice")
+KV_MOVE_MODULES = ("jit_kv_scatter_layer",)
 PAGED_ATTN_OPS = (("jit_forward_decode_step|", " tpu_custom_call"),)
 
 

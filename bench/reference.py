@@ -1,9 +1,9 @@
 """The plain reference a served stream is held to, and the comparison.
 
-``logits`` is a causal forward of a dense decoder in plain ``jax.numpy``
-from a configuration file's sizes and a weight tree: full attention over
-the whole sequence, no paged pool, no kernel, no cache, no batching. It
-imports nothing of the serving program. ``served_gaps`` runs it once over
+``logits`` is the configuration's family's causal forward in plain
+``jax.numpy`` (``bench/families/<family>.py``): full attention over the
+whole sequence, no paged pool, no kernel, no cache, no batching, and
+nothing of the serving program. ``served_gaps`` runs it once over
 a prompt and the tokens served for it, in float32 at the matrix-product
 precision the configuration states (``matmul_precision``), and reads, at
 every served position, how far the served token's logit lies below the
@@ -15,7 +15,6 @@ control that the limit has to reject.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 from typing import Dict, List, Optional, Sequence
 
@@ -23,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import dims
+from bench import families
 
 # Sequences are padded to a multiple of this, so the reference compiles
 # for few lengths.
@@ -33,58 +32,10 @@ PAD = 512
 def logits(c: Dict, params, tokens: Optional[jax.Array], *,
            hidden: Optional[jax.Array] = None,
            pos: Optional[jax.Array] = None) -> jax.Array:
-    """(b, s) int32 tokens -> (b, s, vocab) float32 logits. With
-    ``hidden`` (b, s, d) given instead, the layers alone run on it and the
-    hidden state comes back (the control runs layer by layer)."""
-    m = dims(c)
-    b, s = (tokens if hidden is None else hidden).shape[:2]
-    nh, nkv, hd = m["nh"], m["nkv"], m["hd"]
-    half = hd // 2
-    inv_freq = c["rope_theta"] ** (-jnp.arange(half, dtype=jnp.float32)
-                                   / half)
-    if pos is None:
-        pos = jnp.arange(s)
-    ang = pos.astype(jnp.float32)[:, None] * inv_freq
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    eps = c["rms_norm_eps"]
-    dt = (params["embed"] if hidden is None else hidden).dtype
-
-    def norm(x, w):
-        xf = x.astype(jnp.float32)
-        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        return (xf * jax.lax.rsqrt(ms + eps)).astype(dt) * w
-
-    def rotate(x):                              # (b, s, heads, hd)
-        x1, x2 = x[..., :half], x[..., half:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                               axis=-1).astype(dt)
-
-    def layer(h, p):
-        x = norm(h, p["norm"])
-        q, k, v = x @ p["wq"], x @ p["wk"], x @ p["wv"]
-        q = rotate(q.reshape(b, s, nh, hd))
-        k = rotate(k.reshape(b, s, nkv, hd))
-        v = v.reshape(b, s, nkv, hd)
-        k = jnp.repeat(k, nh // nkv, axis=2)
-        v = jnp.repeat(v, nh // nkv, axis=2)
-        sc = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-        sc = jnp.where(causal, sc / math.sqrt(hd), -jnp.inf)
-        pr = jax.nn.softmax(sc, axis=-1).astype(dt)
-        o = jnp.einsum("bhqk,bkhd->bqhd", pr, v)
-        h = h + o.reshape(b, s, nh * hd) @ p["wo"]
-        x = norm(h, p["norm2"])
-        f = p["mlp"]
-        h = h + (jax.nn.silu(x @ f["w_gate"]) * (x @ f["w_up"])) @ f["w_down"]
-        return h, None
-
-    h = params["embed"][tokens] if hidden is None else hidden
-    h, _ = jax.lax.scan(layer, h, params["blocks"]["sub0"])
-    if hidden is not None:
-        return h
-    h = norm(h, params["final_norm"])
-    head = params["embed"].T if m["tied"] else params["lm_head"]
-    return (h @ head).astype(jnp.float32)
+    """The family's plain reference: (b, s) int32 tokens -> (b, s, vocab)
+    float32 logits; with ``hidden`` (b, s, d) given instead, the layers
+    alone run on it at positions ``pos`` and the hidden state comes back."""
+    return families.of(c).logits(c, params, tokens, hidden=hidden, pos=pos)
 
 
 @partial(jax.jit, static_argnames=("c",))
@@ -105,17 +56,8 @@ def _gaps(c, params, tokens, served, pick, mask):
 @partial(jax.jit, static_argnames=("c", "dtype"))
 def _control_layer(c, dtype, h, p, pos):
     """One layer of the reference with its weights cast to ``dtype``."""
-    one = jax.tree.map(lambda x: x.astype(dtype)[None], p)
-    return logits(c, {"blocks": {"sub0": one}}, None, hidden=h, pos=pos)
-
-
-@partial(jax.jit, static_argnames=("c", "dtype"))
-def _control_head(c, dtype, h, final_norm, head):
-    hf = h.astype(jnp.float32)
-    ms = jnp.mean(hf * hf, axis=-1, keepdims=True)
-    x = (hf * jax.lax.rsqrt(ms + c["rms_norm_eps"])).astype(dtype) \
-        * final_norm.astype(dtype)
-    return jnp.argmax((x @ head.astype(dtype)).astype(jnp.float32), -1)
+    one = jax.tree.map(lambda x: x.astype(dtype), p)
+    return families.of(c).control_layer(c, one, h, pos)
 
 
 def control_tokens(c: Dict, params, tokens: jax.Array,
@@ -123,16 +65,13 @@ def control_tokens(c: Dict, params, tokens: jax.Array,
     """The token the reference computed in ``dtype`` ranks first at every
     position: weights cast one layer at a time, so the control never holds
     a second copy of the model."""
-    m = dims(c)
+    fam = families.of(c)
     fc = _frozen(c)
-    h = params["embed"][tokens].astype(dtype)
+    h = fam.control_embed(fc, params, tokens).astype(dtype)
     pos = jnp.arange(tokens.shape[1])
-    blocks = params["blocks"]["sub0"]
-    for layer in range(m["layers"]):
-        p = jax.tree.map(lambda x: x[layer], blocks)
+    for p in fam.control_layers(fc, params):
         h = _control_layer(fc, dtype, h, p, pos)
-    head = params["embed"].T if m["tied"] else params["lm_head"]
-    return _control_head(fc, dtype, h, params["final_norm"], head)
+    return fam.control_head(fc, dtype, h, params)
 
 
 def _arrays(prompt: Sequence[int], served: Sequence[int]):

@@ -52,7 +52,8 @@ def parse(argv=None):
 def per_layer(cell, ctx) -> dict:
     out = {}
     for m in cell.per_layer:
-        reader = H.load_module(H.BENCH / "metrics" / f"{m['name']}.py")
+        reader = H.load_module(cell.root / "bench" / "metrics"
+                               / f"{m['name']}.py")
         v = reader.read(ctx)
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}

@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import flops
+from bench import families, flops
 from bench.harness import load_json
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
@@ -23,24 +23,26 @@ def nemo():
     return {"hidden_size": 5120, "intermediate_size": 14336,
             "num_attention_heads": 32, "num_key_value_heads": 8,
             "head_dim": 128, "num_hidden_layers": 4, "vocab_size": 131072,
-            "tie_word_embeddings": False}
+            "tie_word_embeddings": False, "family": "dense"}
 
 
 def test_granite_weights(granite):
+    dense = families.of(granite)
     # per layer: q 4096x4096, k and v 4096x1024, o 4096x4096, MLP 3x4096x12800
     layer = 16_777_216 + 2 * 4_194_304 + 16_777_216 + 157_286_400
     assert layer == 199_229_440
-    assert flops.matmul_params(granite, head=False) == 8 * layer
-    assert flops.matmul_params(granite) == 8 * layer + 4096 * 49155
-    assert flops.kv_bytes_per_token(granite) == 8 * 2 * 1024 * 4
+    assert dense.matmul_params(granite, head=False) == 8 * layer
+    assert dense.matmul_params(granite) == 8 * layer + 4096 * 49155
+    assert dense.kv_bytes_per_token(granite) == 8 * 2 * 1024 * 4
 
 
 def test_nemo_weights_have_a_narrower_query(nemo):
+    dense = families.of(nemo)
     # q width 32 x 128 = 4096 under d_model 5120
     layer = 5120 * 4096 + 2 * 5120 * 1024 + 4096 * 5120 + 3 * 5120 * 14336
     assert layer == 272_629_760
-    assert flops.matmul_params(nemo) == 4 * layer + 5120 * 131072
-    assert flops.weight_bytes(nemo) == 7_046_430_720
+    assert dense.matmul_params(nemo) == 4 * layer + 5120 * 131072
+    assert dense.weight_bytes(nemo) == 7_046_430_720
 
 
 def test_granite_prefill_of_512_tokens(granite):

@@ -29,6 +29,10 @@ def root(tmp_path_factory):
     (r / "bench" / "traffic").mkdir(parents=True)
     shutil.copy(DATA / "tiny.json", r / "bench" / "configs" / "tiny.json")
     shutil.copy(DATA / "tiny_open.json", r / "bench" / "traffic" / "tiny_open.json")
+    shutil.copy(H.BENCH / "traffic" / "gen_requests.py",
+                r / "bench" / "traffic" / "gen_requests.py")
+    shutil.copytree(H.BENCH / "families", r / "bench" / "families",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     b = json.loads((H.ROOT / "BENCHMARK.json").read_text())
     b["configs"] = [{"name": "tiny", "source": "test", "reduced": [],
                      "file": "bench/configs/tiny.json", "why": "test"}]
@@ -58,15 +62,7 @@ def test_sound_run_is_correct_and_builds_nothing_in_the_window(root, capsys):
     assert out["check"]["checked_tokens"]["value"] >= H.MIN_CHECKED
 
 
-def test_altered_token_is_caught(root, monkeypatch):
-    from repro.serving.engine import DecodeEngine
-    step = DecodeEngine._step_fused
-
-    def altered(self):
-        out = step(self)
-        return {s: (t + 1) % self.cfg.vocab_size for s, t in out.items()}
-
-    monkeypatch.setattr(DecodeEngine, "_step_fused", altered)
+def test_altered_token_is_caught(root, altered_tokens):
     out = _run(root)
     assert not out["correct"]
     assert out["check"]["past_near_tie_pct"]["value"] > LIMIT

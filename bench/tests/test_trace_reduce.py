@@ -89,3 +89,34 @@ def test_recorded_chip_trace():
     assert any(k.startswith("?|") for k in r["ops"])
     assert sum(r["idle_by_host"].values()) == pytest.approx(
         r["window_s"] - r["busy_s"])
+
+
+def test_kv_move_reads_the_cached_per_layer_scatter():
+    """Three runs of the hand-off's cached per-layer scatter (40, 60 and
+    20 ns, two of them one program) for two requests handed to decode:
+    60 ns, 6e-5 ms, a request. The programs of the eager hand-off before
+    it (``jit_slice``, ``jit_dynamic_update_slice``, ``jit_wrapped``) and
+    the whole-buffer scatter are not counted."""
+    from bench.harness import BENCH, load_module
+    reader = load_module(BENCH / "metrics" / "kv_move_ms_per_req.py")
+    evs = [
+        ("host", "main", "bench.window", 0, 1000),
+        ("device:0", "XLA Modules", "jit_kv_scatter_layer(7)", 100, 40),
+        ("device:0", "XLA Modules", "jit_kv_scatter_layer(7)", 200, 60),
+        ("device:0", "XLA Modules", "jit_kv_scatter_layer(9)", 300, 20),
+        ("device:0", "XLA Modules", "jit_dynamic_update_slice(2)", 400, 50),
+        ("device:0", "XLA Modules", "jit_slice(4)", 500, 50),
+        ("device:0", "XLA Modules", "jit_wrapped(5)", 600, 50),
+        ("device:0", "XLA Modules", "jit_kv_scatter(3)", 700, 50),
+    ] + [("device:0", "XLA Ops", "%closed_call.1 = f32[8,16]{1,0} "
+          "custom-call(f32[8,16] %a), custom_call_target=\"tpu_custom_call\"",
+          s, d) for s, d in ((100, 40), (200, 60), (300, 20))]
+    red = TR.reduce(evs)
+    ctx = {"trace": red, "counters": {"handed_to_decode": 2.0}}
+    assert reader.read(ctx) == pytest.approx(1e3 * 120e-9 / 2)
+    assert reader.read({"trace": red,
+                        "counters": {"handed_to_decode": 0.0}}) is None
+    # a window with no scatter in it reads nothing, not 0
+    none = [e for e in evs if "kv_scatter_layer" not in e[2]]
+    assert reader.read({"trace": TR.reduce(none),
+                        "counters": {"handed_to_decode": 2.0}}) is None

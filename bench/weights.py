@@ -1,10 +1,8 @@
-"""Seeded weights for a dense decoder, made on the device in one call.
+"""Seeded weights, made on the device in one call.
 
-The tree has the layout the serving program takes (``embed``,
-``final_norm``, optional ``lm_head``, and ``blocks/sub0`` with every
-layer's matrices stacked on a leading axis). The benchmark makes it, so
-the plain reference and the program read the same numbers and neither
-made them.
+The tree's layout is the family's (``families.of(c).shapes``), the one
+the serving program takes. The benchmark makes it, so the plain
+reference and the program read the same numbers and neither made them.
 """
 from __future__ import annotations
 
@@ -13,33 +11,9 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from bench import families
+
 STD = 0.02
-
-
-def dims(c: Dict) -> Dict[str, int]:
-    """Sizes of a configuration file, under short names."""
-    hd = c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
-    return {"d": c["hidden_size"], "nh": c["num_attention_heads"],
-            "nkv": c["num_key_value_heads"], "hd": hd,
-            "ff": c["intermediate_size"], "vocab": c["vocab_size"],
-            "layers": c["num_hidden_layers"],
-            "tied": bool(c.get("tie_word_embeddings", False))}
-
-
-def shapes(c: Dict) -> Dict:
-    """Shape of every leaf of the weight tree."""
-    m = dims(c)
-    d, L, ff = m["d"], m["layers"], m["ff"]
-    q, kv = m["nh"] * m["hd"], m["nkv"] * m["hd"]
-    t = {"embed": (m["vocab"], d), "final_norm": (d,),
-         "blocks": {"sub0": {
-             "norm": (L, d), "wq": (L, d, q), "wk": (L, d, kv),
-             "wv": (L, d, kv), "wo": (L, q, d), "norm2": (L, d),
-             "mlp": {"w_gate": (L, d, ff), "w_up": (L, d, ff),
-                     "w_down": (L, ff, d)}}}}
-    if not m["tied"]:
-        t["lm_head"] = (d, m["vocab"])
-    return t
 
 
 def _is_shape(x) -> bool:
@@ -47,9 +21,11 @@ def _is_shape(x) -> bool:
 
 
 def make(c: Dict, seed: int, dtype=jnp.float32):
-    """Normal(0, STD) matrices and unit norm gains from ``seed``, built by
-    one jitted call so they never pass through the host."""
-    tree = shapes(c)
+    """Normal(0, STD) matrices and unit norm gains from ``seed``, and the
+    family's values for any other leaf, built by one jitted call so they
+    never pass through the host."""
+    fam = families.of(c)
+    tree = fam.shapes(c)
     leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_shape)
     paths = [jax.tree_util.keystr(p) for p, _ in
              jax.tree_util.tree_flatten_with_path(tree, is_leaf=_is_shape)[0]]
@@ -58,7 +34,10 @@ def make(c: Dict, seed: int, dtype=jnp.float32):
         keys = jax.random.split(key, len(leaves))
         out = []
         for path, shape, k in zip(paths, leaves, keys):
-            if "norm" in path:
+            own = fam.init_leaf(path, shape, k, dtype)
+            if own is not None:
+                out.append(own)
+            elif "norm" in path:
                 out.append(jnp.ones(shape, dtype))
             else:
                 out.append((jax.random.normal(k, shape, jnp.float32)
